@@ -33,7 +33,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.core.grouping import N_GROUPS
@@ -45,14 +44,9 @@ from repro.core.stats import (
     pad_to_tiles,
 )
 from repro.distributed.sharding import TILE_AXIS, tile_mesh
+from repro.kernels import resolve_interpret
 
 StatsTuple = Tuple[jax.Array, jax.Array, jax.Array, jax.Array]
-
-
-def _default_interpret() -> bool:
-    # the Pallas kernel only compiles on TPU; everywhere else run the
-    # interpreter (tests/benchmarks) — callers can still force either way.
-    return jax.default_backend() != "tpu"
 
 
 def gather_layer_tiles(
@@ -216,7 +210,6 @@ def batched_layer_stats(
     if use_kernel:
         from repro.kernels.transition_energy import ops as te_ops
 
-        interpret = _default_interpret() if interpret is None else interpret
         return te_ops.batched_transition_stats(
             w_tiles, a_blocks, coeffs, mask=mask, interpret=interpret)
     return batched_stats_oracle(w_tiles, a_blocks, mask, coeffs,
@@ -242,8 +235,7 @@ def sharded_layer_stats(
     """
     mesh = tile_mesh() if mesh is None else mesh
     n_dev = mesh.shape[TILE_AXIS]
-    if use_kernel and (interpret or (interpret is None and
-                                     _default_interpret())):
+    if use_kernel and resolve_interpret(interpret):
         # Pallas interpret mode inside shard_map deadlocks on host devices;
         # interpret is a CPU-only correctness tool anyway, so the sharded
         # path falls back to the vectorized oracle (identical statistics).
@@ -264,8 +256,11 @@ def sharded_layer_stats(
         return jax.tree.map(lambda x: jax.lax.psum(x, TILE_AXIS), out)
 
     spec = PartitionSpec(TILE_AXIS)
-    return shard_map(local, mesh, in_specs=(spec, spec, spec),
-                     out_specs=PartitionSpec())(w_tiles, a_blocks, mask)
+    # check_vma=False: the Pallas kernel's out_shape carries no varying-axes
+    # annotation, which the checker would require inside shard_map
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=PartitionSpec(),
+                         check_vma=False)(w_tiles, a_blocks, mask)
 
 
 def profile_layer(

@@ -156,7 +156,6 @@ class CnnRunner:
             return (self._cand_train_step, self._cand_eval_step,
                     self._comp_eval_step)
         if self._sweep_sharded is None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec
             from repro.distributed.sharding import SWEEP_AXIS
 
@@ -167,15 +166,15 @@ class CnnRunner:
             ve = jax.vmap(self._eval_step_raw, in_axes=(0, 0, 0, None))
             vc = jax.vmap(self._eval_step_raw, in_axes=(None, None, 0, None))
             self._sweep_sharded = (
-                jax.jit(shard_map(
-                    vt, mesh, in_specs=(cand, cand, cand, cand, rep),
-                    out_specs=cand, check_rep=False)),
-                jax.jit(shard_map(
-                    ve, mesh, in_specs=(cand, cand, cand, rep),
-                    out_specs=cand, check_rep=False)),
-                jax.jit(shard_map(
-                    vc, mesh, in_specs=(rep, rep, cand, rep),
-                    out_specs=cand, check_rep=False)),
+                jax.jit(jax.shard_map(
+                    vt, mesh=mesh, in_specs=(cand, cand, cand, cand, rep),
+                    out_specs=cand, check_vma=False)),
+                jax.jit(jax.shard_map(
+                    ve, mesh=mesh, in_specs=(cand, cand, cand, rep),
+                    out_specs=cand, check_vma=False)),
+                jax.jit(jax.shard_map(
+                    vc, mesh=mesh, in_specs=(rep, rep, cand, rep),
+                    out_specs=cand, check_vma=False)),
             )
         return self._sweep_sharded
 

@@ -36,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 N_CODES = 16
 
@@ -61,11 +62,12 @@ def _unpack_tile(packed, pack_block: int):
 
 def _dequant(packed, cb_ref, scale_ref, pack_block: int):
     idx = _unpack_tile(packed, pack_block)
-    # 16-way select instead of gather: w = sum_c (idx == c) * cb[c]
+    # 16-way select instead of gather: w = sum_c (idx == c) * cb[c], with
+    # each cb[c] a scalar read from the int32 codebook in scalar memory
     w = jnp.zeros(idx.shape, jnp.float32)
     for c in range(N_CODES):
         w = w + jnp.where(idx == c, cb_ref[c].astype(jnp.float32), 0.0)
-    return w * scale_ref[...].astype(jnp.float32)[None, :]  # per-out-channel
+    return w * scale_ref[...].astype(jnp.float32)  # (1, bn) per-out-channel
 
 
 def _kernel(x_ref, packed_ref, cb_ref, scale_ref, *rest,
@@ -96,7 +98,7 @@ def _kernel(x_ref, packed_ref, cb_ref, scale_ref, *rest,
     def _finalize():
         y = o_ref[...] + acc
         if has_bias:
-            y = y + bias_ref[...].astype(jnp.float32)[None, :]
+            y = y + bias_ref[...].astype(jnp.float32)
         y = ACTIVATIONS[activation](y)
         if has_residual:
             y = y + res_ref[...].astype(jnp.float32)
@@ -125,7 +127,7 @@ def _check_blocks(m, k, n, k2, block_m, block_n, block_k, pack_block):
 def lut_matmul_pallas(
     x: jax.Array,            # (M, K) float
     packed: jax.Array,       # (K//2, N) int8 packed 4-bit indices
-    codebook: jax.Array,     # (16,) int8/int32 codebook values
+    codebook: jax.Array,     # (16,) int8/int32 codebook values (SMEM)
     scale: jax.Array,        # (N,) float per-channel dequant scale
     *,
     bias: jax.Array | None = None,       # (N,) fused bias add
@@ -154,18 +156,20 @@ def lut_matmul_pallas(
         _kernel, pack_block=pack_block, grid_k=grid[2], activation=activation,
         has_bias=has_bias, has_residual=has_residual)
 
+    # per-channel vectors ride as (1, N) rows: Mosaic tiles 2-D blocks only
+    row_spec = pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j))
     in_specs = [
         pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((block_k // 2, block_n), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((N_CODES,), lambda i, j, kk: (0,)),
-        pl.BlockSpec((block_n,), lambda i, j, kk: (j,)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        row_spec,
     ]
-    args = [x, packed, codebook, scale]
+    args = [x, packed, codebook.astype(jnp.int32), scale.reshape(1, n)]
     if has_bias:
         if bias.shape != (n,):
             raise ValueError(f"bias shape {bias.shape} != ({n},)")
-        in_specs.append(pl.BlockSpec((block_n,), lambda i, j, kk: (j,)))
-        args.append(bias)
+        in_specs.append(row_spec)
+        args.append(bias.reshape(1, n))
     if has_residual:
         if residual.shape != (m, n):
             raise ValueError(f"residual shape {residual.shape} != {(m, n)}")

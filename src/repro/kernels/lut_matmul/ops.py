@@ -8,18 +8,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.lut_matmul.lut_matmul import N_CODES, lut_matmul_pallas
 from repro.kernels.lut_matmul.ref import lut_matmul_fused_ref
-
-
-def default_interpret() -> bool:
-    """Backend-aware Pallas mode: compiled on TPU, interpreted elsewhere.
-
-    The LUT GEMM is a TPU kernel; on CPU/GPU hosts (tests, reduced serving
-    configs) interpret mode runs the same program through the Pallas
-    interpreter so the packed serving path stays executable everywhere.
-    """
-    return jax.default_backend() != "tpu"
 
 
 def encode_weights(w_int: jax.Array, codebook: jax.Array):
@@ -107,8 +98,8 @@ def lut_matmul_fused(
     multiple — packing is block-local). Block shapes left as ``None`` resolve
     through the roofline autotuner (`repro.kernels.lut_matmul.autotune`),
     cached per (M, K, N, pack_block, backend) fingerprint. ``interpret=None``
-    resolves per backend (`default_interpret`): compiled Pallas on TPU,
-    interpreter elsewhere.
+    resolves per backend (`repro.kernels.resolve_interpret`): compiled
+    Pallas on TPU, interpreter elsewhere.
     """
     m, k = x.shape
     _, n = packed.shape
@@ -116,8 +107,7 @@ def lut_matmul_fused(
         raise ValueError(
             f"K={k} must already be a multiple of pack_block={pack_block} "
             "(packing is block-local; pad K at export)")
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     if use_ref:
         # the ref oracle ignores block shapes — don't touch the autotuner
         block_m = block_n = block_k = pack_block

@@ -10,18 +10,21 @@ tile, the partial-sum transition sequence and accumulating:
 This replaces the paper's ModelSim gate-level inner loop and dominates
 profiling time, so it gets a kernel. TPU mapping decisions:
 
-  * grid = (T-1,): one program per streaming transition t -> t+1; the psum
-    prefix over the K axis is recomputed per step (two 64x64 cumsums, cheap)
-    instead of carrying systolic state — grid steps stay independent.
-  * histogram scatter is re-expressed as ONE-HOT MATMULS on the MXU
-    (onehot(prev)^T @ onehot(cur) / onehot(bins)^T @ energy): no gathers or
-    scatters, which TPUs hate; the biggest one-hot tile is (4096, 256) f32 =
-    4 MiB, inside VMEM.
+  * grid = (n_tiles, T-1): one program per tile and streaming transition
+    t -> t+1; the psum prefix over the K axis is recomputed per step (two
+    64x64 triangular matmuls) instead of carrying systolic state — grid
+    steps stay independent.
+  * the pair histograms are ONE-HOT MATMULS on the MXU
+    (onehot(prev)^T @ onehot(cur)): no gathers or scatters, which TPUs
+    hate. The per-weight-value energy sums are a masked reduction of a
+    (64, 64, 256) one-hot on the VPU, which keeps them in f32.
   * all outputs revisit the same VMEM blocks across the grid (accumulation
-    pattern with pl.when(t == 0) init).
+    pattern with pl.when init at the first step).
 
-Bit-level ops (population_count / clz) run on the VPU; validated in
-interpret mode against the `repro.core.stats` oracle.
+Every op is one Mosaic lowers: bit-level ops (population_count / clz) run
+on the VPU, and every BlockSpec is tiling-legal. Validated in interpret
+mode against the `repro.core.stats` oracle, and bit-exactly against the
+cosim (`repro.cosim`).
 """
 
 from __future__ import annotations
@@ -31,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.mac_model import MacEnergyCoeffs
 
@@ -82,104 +86,84 @@ def _energy(w, a_prev, a_cur, p_prev, p_cur, c: MacEnergyCoeffs):
     return jnp.where(w == 0, gated, active) + jnp.float32(c.c_base)
 
 
-def _onehot_f32(idx, n):
-    return (idx[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-            ).astype(jnp.float32)
+def _onehot(idx, n):
+    """One-hot of an int array along a new minor (lane) axis: (..., n)."""
+    bins = jax.lax.broadcasted_iota(jnp.int32, (1,) * idx.ndim + (n,),
+                                    idx.ndim)
+    return idx[..., None] == bins
+
+
+def _column(a_blk, t):
+    """Column ``t`` of a (K, T) block as (K, 1), by a masked lane sum.
+
+    Mosaic cannot slice a lane at a dynamic offset; the sum picks exactly
+    one element per row, so it is exact."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, a_blk.shape, 1)
+    return jnp.sum(jnp.where(lane == t, a_blk, 0), axis=1, keepdims=True)
+
+
+def _prefix_sum_rows(x):
+    """Inclusive prefix sum over axis 0 of a (K, M) int32 tile, |x| < 2**16.
+
+    Mosaic has no cumsum, so a lower-triangular 0/1 matmul runs it on the
+    MXU. bf16 holds integers up to 256 exactly, so x goes in as its high
+    and low bytes; each part sums in f32, which is exact below 2**24."""
+    k = x.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
+    tril = (rows >= cols).astype(jnp.bfloat16)
+
+    def part(v):
+        return jnp.dot(tril, v.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    return part(x >> 8) * 256 + part(x & 0xFF)
+
+
+_CONTRACT_ROWS = (((0,), (0,)), ((), ()))   # A^T @ B without a transpose
+
+
+def _pair_hist(oh_prev, oh_cur):
+    """(n, n) counts of (prev, cur) bin pairs from two (rows, n) one-hots.
+
+    0/1 one-hots are exact in bf16 and the counts are exact in f32."""
+    return jax.lax.dot_general(oh_prev.astype(jnp.bfloat16),
+                               oh_cur.astype(jnp.bfloat16), _CONTRACT_ROWS,
+                               preferred_element_type=jnp.float32)
 
 
 def _accumulate(w, a_prev, a_cur, scale, esum_ref, cnt_ref, ghist_ref,
                 ahist_ref, coeffs: MacEnergyCoeffs):
     """Accumulate one streaming transition of one tile into the output refs.
 
-    w: (K, M) int32 stationary weights; a_prev/a_cur: (K,) int32 activation
-    columns; scale: f32 weighting (1 for real tiles, 0 for batch padding).
+    w: (K, M) int32 stationary weights; a_prev/a_cur: (K, 1) int32
+    activation columns; scale: f32 weighting (1 for real tiles, 0 for batch
+    padding). Histograms go over the MAC grid as 3-D one-hots whose leading
+    two dims merge into rows; a (K, M) -> (K*M,) reshape is not legal in
+    Mosaic.
     """
     # systolic column prefix sums at t and t+1
-    p_prev = jnp.cumsum(w * a_prev[:, None], axis=0)     # (K, M)
-    p_cur = jnp.cumsum(w * a_cur[:, None], axis=0)
+    p_prev = _prefix_sum_rows(w * a_prev)                # (K, M)
+    p_cur = _prefix_sum_rows(w * a_cur)
 
-    e = _energy(w, a_prev[:, None], a_cur[:, None], p_prev, p_cur, coeffs)
+    e = _energy(w, a_prev, a_cur, p_prev, p_cur, coeffs)
 
-    n = TILE * TILE
-    w_bins = (w + 128).reshape(n)
-    onehot_w = _onehot_f32(w_bins, N_WVALS)              # (4096, 256)
-    e_flat = e.reshape(n, 1)
-    esum_ref[...] += scale * jnp.dot(onehot_w.T, e_flat,
-                                     preferred_element_type=jnp.float32)[:, 0]
-    cnt_ref[...] += scale * jnp.sum(onehot_w, axis=0)
+    oh_w = _onehot(w + 128, N_WVALS)                     # (K, M, 256)
+    e_w = jnp.where(oh_w, e[:, :, None], 0.0).reshape(-1, N_WVALS)
+    esum_ref[...] += scale * jnp.sum(e_w, axis=0, keepdims=True)
+    cnt_ref[...] += scale * jnp.sum(
+        oh_w.astype(jnp.float32).reshape(-1, N_WVALS), axis=0, keepdims=True)
 
-    g_prev = _group_id(p_prev).reshape(n)
-    g_cur = _group_id(p_cur).reshape(n)
-    oh_gp = _onehot_f32(g_prev, N_GROUPS)
-    oh_gc = _onehot_f32(g_cur, N_GROUPS)
-    ghist_ref[...] += scale * jnp.dot(oh_gp.T, oh_gc,
-                                      preferred_element_type=jnp.float32)
-
-    oh_ap = _onehot_f32(a_prev + 128, N_WVALS)           # (64, 256)
-    oh_ac = _onehot_f32(a_cur + 128, N_WVALS)
-    ahist_ref[...] += scale * jnp.dot(oh_ap.T, oh_ac,
-                                      preferred_element_type=jnp.float32)
+    ghist_ref[...] += scale * _pair_hist(
+        _onehot(_group_id(p_prev), N_GROUPS).reshape(-1, N_GROUPS),
+        _onehot(_group_id(p_cur), N_GROUPS).reshape(-1, N_GROUPS))
+    act_bins = jax.lax.broadcasted_iota(jnp.int32, (1, N_WVALS), 1)
+    ahist_ref[...] += scale * _pair_hist(a_prev + 128 == act_bins,
+                                         a_cur + 128 == act_bins)
 
 
-def _kernel(w_ref, a_prev_ref, a_cur_ref, esum_ref, cnt_ref, ghist_ref,
-            ahist_ref, *, coeffs: MacEnergyCoeffs):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        esum_ref[...] = jnp.zeros_like(esum_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-        ghist_ref[...] = jnp.zeros_like(ghist_ref)
-        ahist_ref[...] = jnp.zeros_like(ahist_ref)
-
-    w = w_ref[...].astype(jnp.int32)                     # (K, M)
-    a_prev = a_prev_ref[...].astype(jnp.int32)[:, 0]     # column t
-    a_cur = a_cur_ref[...].astype(jnp.int32)[:, 0]       # column t + 1
-    _accumulate(w, a_prev, a_cur, jnp.float32(1.0), esum_ref, cnt_ref,
-                ghist_ref, ahist_ref, coeffs)
-
-
-def transition_stats_pallas(
-    w_tile: jax.Array,       # (64, 64) int32 (K rows x M cols, stationary)
-    a_block: jax.Array,      # (64, T) int32 streamed activations
-    coeffs: MacEnergyCoeffs,
-    *,
-    interpret: bool = False,
-):
-    k, m = w_tile.shape
-    assert (k, m) == (TILE, TILE), (k, m)
-    t_len = a_block.shape[1]
-    assert t_len >= 2
-
-    kernel = functools.partial(_kernel, coeffs=coeffs)
-    out_shapes = (
-        jax.ShapeDtypeStruct((N_WVALS,), jnp.float32),
-        jax.ShapeDtypeStruct((N_WVALS,), jnp.float32),
-        jax.ShapeDtypeStruct((N_GROUPS, N_GROUPS), jnp.float32),
-        jax.ShapeDtypeStruct((N_WVALS, N_WVALS), jnp.float32),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(t_len - 1,),
-        in_specs=[
-            pl.BlockSpec((TILE, TILE), lambda t: (0, 0)),
-            pl.BlockSpec((TILE, 1), lambda t: (0, t)),       # column t
-            pl.BlockSpec((TILE, 1), lambda t: (0, t + 1)),   # column t + 1
-        ],
-        out_specs=(
-            pl.BlockSpec((N_WVALS,), lambda t: (0,)),
-            pl.BlockSpec((N_WVALS,), lambda t: (0,)),
-            pl.BlockSpec((N_GROUPS, N_GROUPS), lambda t: (0, 0)),
-            pl.BlockSpec((N_WVALS, N_WVALS), lambda t: (0, 0)),
-        ),
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(w_tile.astype(jnp.int32), a_block.astype(jnp.int32),
-      a_block.astype(jnp.int32))
-
-
-def _batched_kernel(mask_ref, w_ref, a_prev_ref, a_cur_ref, esum_ref, cnt_ref,
-                    ghist_ref, ahist_ref, *, coeffs: MacEnergyCoeffs):
+def _batched_kernel(mask_ref, w_ref, a_ref, esum_ref, cnt_ref, ghist_ref,
+                    ahist_ref, *, coeffs: MacEnergyCoeffs):
     b = pl.program_id(0)
     t = pl.program_id(1)
 
@@ -190,12 +174,10 @@ def _batched_kernel(mask_ref, w_ref, a_prev_ref, a_cur_ref, esum_ref, cnt_ref,
         ghist_ref[...] = jnp.zeros_like(ghist_ref)
         ahist_ref[...] = jnp.zeros_like(ahist_ref)
 
-    w = w_ref[0].astype(jnp.int32)                       # (K, M) of tile b
-    a_prev = a_prev_ref[0].astype(jnp.int32)[:, 0]       # column t of tile b
-    a_cur = a_cur_ref[0].astype(jnp.int32)[:, 0]         # column t + 1
-    scale = mask_ref[0, 0]                               # 0 for pad tiles
-    _accumulate(w, a_prev, a_cur, scale, esum_ref, cnt_ref, ghist_ref,
-                ahist_ref, coeffs)
+    w = w_ref[0]                                         # (K, M) of tile b
+    a_blk = a_ref[0]                                     # (K, T) of tile b
+    _accumulate(w, _column(a_blk, t), _column(a_blk, t + 1), mask_ref[b],
+                esum_ref, cnt_ref, ghist_ref, ahist_ref, coeffs)
 
 
 def transition_stats_batched_pallas(
@@ -210,11 +192,14 @@ def transition_stats_batched_pallas(
 
     Grid is (n_tiles, T-1): the tile index is the leading block dimension, so
     every sampled tile of a layer streams through one `pallas_call` instead of
-    one kernel dispatch per tile. All four outputs live in the same VMEM
-    blocks across the entire grid (accumulation pattern, initialised at
-    (b, t) == (0, 0)); `mask` lets callers pad `n_tiles` up to a convenient
-    multiple (e.g. the device count) with zero-weight tiles that contribute
-    nothing.
+    one kernel dispatch per tile. Each tile's weights and whole activation
+    block are fetched once and stay in VMEM across its T-1 steps; step t
+    reads columns t and t+1 from the block. All four outputs live in the same
+    VMEM blocks across the entire grid (accumulation pattern, initialised at
+    (b, t) == (0, 0)); `mask`, held in scalar memory, lets callers pad
+    `n_tiles` up to a convenient multiple (e.g. the device count) with
+    zero-weight tiles that contribute nothing. Weights and activations must
+    be int8-valued (the prefix sum relies on it for exactness).
     """
     n_tiles, k, m = w_tiles.shape
     assert (k, m) == (TILE, TILE), (k, m)
@@ -223,31 +208,42 @@ def transition_stats_batched_pallas(
     assert t_len >= 2
     if mask is None:
         mask = jnp.ones((n_tiles,), jnp.float32)
-    mask2d = jnp.asarray(mask, jnp.float32).reshape(n_tiles, 1)
 
     kernel = functools.partial(_batched_kernel, coeffs=coeffs)
     out_shapes = (
-        jax.ShapeDtypeStruct((N_WVALS,), jnp.float32),
-        jax.ShapeDtypeStruct((N_WVALS,), jnp.float32),
+        jax.ShapeDtypeStruct((1, N_WVALS), jnp.float32),
+        jax.ShapeDtypeStruct((1, N_WVALS), jnp.float32),
         jax.ShapeDtypeStruct((N_GROUPS, N_GROUPS), jnp.float32),
         jax.ShapeDtypeStruct((N_WVALS, N_WVALS), jnp.float32),
     )
-    return pl.pallas_call(
+    esum, cnt, ghist, ahist = pl.pallas_call(
         kernel,
         grid=(n_tiles, t_len - 1),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, t: (b, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, TILE, TILE), lambda b, t: (b, 0, 0)),
-            pl.BlockSpec((1, TILE, 1), lambda b, t: (b, 0, t)),
-            pl.BlockSpec((1, TILE, 1), lambda b, t: (b, 0, t + 1)),
+            pl.BlockSpec((1, TILE, t_len), lambda b, t: (b, 0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((N_WVALS,), lambda b, t: (0,)),
-            pl.BlockSpec((N_WVALS,), lambda b, t: (0,)),
+            pl.BlockSpec((1, N_WVALS), lambda b, t: (0, 0)),
+            pl.BlockSpec((1, N_WVALS), lambda b, t: (0, 0)),
             pl.BlockSpec((N_GROUPS, N_GROUPS), lambda b, t: (0, 0)),
             pl.BlockSpec((N_WVALS, N_WVALS), lambda b, t: (0, 0)),
         ),
         out_shape=out_shapes,
         interpret=interpret,
-    )(mask2d, w_tiles.astype(jnp.int32), a_blocks.astype(jnp.int32),
+    )(jnp.asarray(mask, jnp.float32), w_tiles.astype(jnp.int32),
       a_blocks.astype(jnp.int32))
+    return esum[0], cnt[0], ghist, ahist
+
+
+def transition_stats_pallas(
+    w_tile: jax.Array,       # (64, 64) int32 (K rows x M cols, stationary)
+    a_block: jax.Array,      # (64, T) int32 streamed activations
+    coeffs: MacEnergyCoeffs,
+    *,
+    interpret: bool = False,
+):
+    """Single-tile statistics: the batched kernel over a batch of one."""
+    return transition_stats_batched_pallas(w_tile[None], a_block[None],
+                                           coeffs, interpret=interpret)

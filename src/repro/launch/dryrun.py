@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a CPU dry run, on any host: this process and the --all children it starts
+# (which inherit the environment) never claim an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

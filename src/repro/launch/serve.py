@@ -148,6 +148,7 @@ def main(argv=None):
                     help="save the CompressionPlan to BASE.json + BASE.npz")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
     from repro.pipeline import (
         Pipeline,
         PipelineConfig,
@@ -169,6 +170,7 @@ def main(argv=None):
                                max_batch=args.max_batch,
                                temperature=args.temperature),
     )
+    enable_compile_cache()
     pipe = Pipeline(cfg)
     plan = pipe.run_until("serve", verbose=True)
     m = plan.metrics

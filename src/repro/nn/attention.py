@@ -85,16 +85,18 @@ def _project(params, x, qcfg: QuantConfig, comp, name: str, key: str,
     art = None if c is None else c.get("serve")
     if qcfg.enabled and qcfg.comp_mode == "serve" and art is not None:
         # packed 4-bit LUT path (bias fused into the kernel epilogue):
-        # wq/wk/wv are exported in_first as (d, H*hd), wo out_last as (H*hd, d)
+        # wq/wk/wv are exported in_first as (d, H*hd), wo out_last as (H*hd, d).
+        # The kernel returns f32 for bf16 inputs; cast back like the dense path
         from repro.core.export import serve_dense
 
         if key == "wo":
             xin = x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1])
-            return serve_dense(xin, art, use_ref=qcfg.use_ref_kernel)
+            return serve_dense(xin, art,
+                               use_ref=qcfg.use_ref_kernel).astype(x.dtype)
         bias = params[bias_key] if bias_key and bias_key in params else None
         y = serve_dense(x, art,
                         bias=None if bias is None else bias.reshape(-1),
-                        use_ref=qcfg.use_ref_kernel)
+                        use_ref=qcfg.use_ref_kernel).astype(x.dtype)
         return y.reshape(*x.shape[:-1], w.shape[1], w.shape[2])
     if qcfg.enabled:
         w = qat.fake_quant_weight(w, c)
@@ -248,7 +250,9 @@ def decode_attention(
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgk,bkhd->bhgd", p.astype(v_cache.dtype), v_cache)
-    return out.reshape(b, 1, hq, hd)
+    # a cache wider than the compute dtype (f32 cache, bf16 compute) must
+    # not widen the residual stream: the layer scan carries it at q's dtype
+    return out.reshape(b, 1, hq, hd).astype(q.dtype)
 
 
 # ----------------------------------------------------------------- full layer

@@ -74,7 +74,7 @@ def apply_ffn(params, x, cfg: ArchConfig, *, qcfg=QuantConfig.off(), comp=None,
             from repro.core.export import serve_dense
 
             return serve_dense(xin, art, activation=activation,
-                               use_ref=qcfg.use_ref_kernel)
+                               use_ref=qcfg.use_ref_kernel).astype(x.dtype)
         w = params[key]
         w = qat.fake_quant_weight(w, c) if qcfg.enabled else w
         y = jnp.einsum("...k,kn->...n", xin, w.astype(x.dtype))

@@ -178,8 +178,11 @@ def _build_config(args):
 
 
 def _execute(args) -> int:
+    from repro.compile_cache import enable_compile_cache
     from repro.pipeline.pipeline import Pipeline
     from repro.pipeline.plan import CompressionPlan
+
+    enable_compile_cache()
 
     verbose = not args.quiet
     if args.plan_in:

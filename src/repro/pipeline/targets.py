@@ -405,7 +405,7 @@ class LMTarget:
             for s in skips:
                 print(f"  - {s['unit']}: {s['reason']} ({s['detail']})")
 
-    def _serve_handle(self, plan: CompressionPlan, k: int):
+    def serve_handle(self, plan: CompressionPlan, k: int):
         """The single-variant `PlanHandle` the pinned serve stage uses."""
         from repro.serving import PlanHandle
 
@@ -437,14 +437,16 @@ class LMTarget:
                     self.model, k, msr_bits=msr))
         return registry
 
-    def stage_serve(self, plan: CompressionPlan, cfg: PipelineConfig,
-                    verbose: bool = False) -> None:
+    def serve_trace(self, cfg: PipelineConfig):
+        """(shapes, EngineConfig, requests) of the serve stage's trace.
+
+        Deterministic in ``cfg.serve``: the same config gives the same
+        buckets and the same seeded prompts."""
         import jax
 
-        from repro.serving import EngineConfig, ServeRequest, ServingEngine
+        from repro.serving import EngineConfig, ServeRequest
 
         s = cfg.serve
-        k = s.compress_k
         shapes = lm_trace_shapes(s.requests, s.prompt_len, s.new_tokens,
                                  s.mixed, stride=s.mixed_stride)
         p_bucket = max(sh[0] for sh in shapes)
@@ -466,12 +468,21 @@ class LMTarget:
                          tenant=f"tenant{i % 2}")
             for i, (prompt, (_, ntok)) in enumerate(zip(prompts, shapes))
         ]
+        return shapes, ecfg, requests
+
+    def stage_serve(self, plan: CompressionPlan, cfg: PipelineConfig,
+                    verbose: bool = False) -> None:
+        from repro.serving import ServingEngine
+
+        s = cfg.serve
+        k = s.compress_k
+        shapes, ecfg, requests = self.serve_trace(cfg)
 
         if s.plans or s.plans_dir:
             self._serve_fleet(plan, cfg, ecfg, shapes, requests, verbose)
             return
 
-        handle = self._serve_handle(plan, k)
+        handle = self.serve_handle(plan, k)
 
         def drain(mode):
             engine = ServingEngine(self.model, plan.params, mode=mode,

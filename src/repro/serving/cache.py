@@ -116,6 +116,20 @@ class ServeCompileCache:
 
     # ------------------------------------------------------------ step fns
 
+    def _compile(self, fn, params, *args, donate: bool = False):
+        """AOT-compile ``fn(params, comp, *args)``; return it as
+        ``(params, *args) -> out``.
+
+        The comp tree is an argument of the executable, not a constant
+        closed over: a packed plan at published widths holds hundreds of MB
+        that would otherwise be copied into every executable. ``donate``
+        donates the first of ``args`` (the decode cache)."""
+        compiled = jax.jit(fn, donate_argnums=(2,) if donate else ()).lower(
+            params, self.comp, *args).compile()
+        self.compile_count += 1
+        comp = self.comp
+        return lambda p, *a: compiled(p, comp, *a)
+
     def _key(self, bucket: BucketSpec) -> Tuple:
         return (self.arch, self.fingerprint, bucket.key())
 
@@ -126,27 +140,25 @@ class ServeCompileCache:
             return self._steps[key]
 
         model, cfg = self.model, self.config
-        qcfg, comp = self.qcfg, self.comp
+        qcfg = self.qcfg
         cache_dtype = jnp.dtype(cfg.cache_dtype)
 
-        def prefill_fn(p, prompts):
+        def prefill_fn(p, c, prompts):
             return model.prefill(p, prompts, max_len=bucket.total_len,
-                                 qcfg=qcfg, comp=comp, cache_dtype=cache_dtype,
+                                 qcfg=qcfg, comp=c, cache_dtype=cache_dtype,
                                  q_block=cfg.q_block, kv_block=cfg.kv_block)
 
-        def decode_fn(p, cache, tok):
-            return model.decode_step(p, cache, tok, qcfg=qcfg, comp=comp)
+        def decode_fn(p, c, cache, tok):
+            return model.decode_step(p, cache, tok, qcfg=qcfg, comp=c)
 
         prompts0 = self._place(
             jnp.zeros((bucket.batch, bucket.prompt_len), jnp.int32))
-        prefill_c = jax.jit(prefill_fn).lower(params, prompts0).compile()
-        self.compile_count += 1
+        prefill_c = self._compile(prefill_fn, params, prompts0)
         # lower decode from a *concrete* prefill output so avals (and, under
         # an optional serving mesh, shardings) match the runtime cache exactly
         _, cache0 = prefill_c(params, prompts0)
         tok0 = self._place(jnp.zeros((bucket.batch, 1), jnp.int32))
-        decode_c = jax.jit(decode_fn, donate_argnums=(1,)).lower(params, cache0, tok0).compile()
-        self.compile_count += 1
+        decode_c = self._compile(decode_fn, params, cache0, tok0, donate=True)
 
         step = CompiledStep(bucket=bucket, prefill=prefill_c, decode=decode_c)
         self._steps[key] = step
@@ -175,18 +187,17 @@ class ServeCompileCache:
         if key in self._steps:
             return self._steps[key]
 
-        model, qcfg, comp = self.model, self.qcfg, self.comp
+        model, qcfg = self.model, self.qcfg
 
-        def decode_fn(p, cache, tok, active):
-            return model.decode_step(p, cache, tok, qcfg=qcfg, comp=comp,
+        def decode_fn(p, c, cache, tok, active):
+            return model.decode_step(p, cache, tok, qcfg=qcfg, comp=c,
                                      active=active)
 
         cache0 = self._group_cache_zero()
         tok0 = self._rep(jnp.zeros((batch, 1), jnp.int32))
         act0 = self._rep(jnp.zeros((batch,), bool))
-        decode_c = jax.jit(decode_fn, donate_argnums=(1,)).lower(params, cache0, tok0,
-                                            act0).compile()
-        self.compile_count += 1
+        decode_c = self._compile(decode_fn, params, cache0, tok0, act0,
+                                 donate=True)
         step = GroupStep(batch=batch, total_len=total_len, decode=decode_c,
                          make_cache=self._group_cache_zero)
         self._steps[key] = step
@@ -203,12 +214,12 @@ class ServeCompileCache:
         if key in self._steps:
             return self._steps[key]
 
-        model, qcfg, comp = self.model, self.qcfg, self.comp
+        model, qcfg = self.model, self.qcfg
 
-        def chunk_fn(p, cache, tokens, row_ids, start, active):
+        def chunk_fn(p, c, cache, tokens, row_ids, start, active):
             row_cache = model.gather_cache_rows(cache, row_ids)
             logits, new_rows = model.prefill_chunk(
-                p, row_cache, tokens, start=start, qcfg=qcfg, comp=comp,
+                p, row_cache, tokens, start=start, qcfg=qcfg, comp=c,
                 q_block=cfg.q_block, kv_block=cfg.kv_block)
             new_cache = model.scatter_cache_rows(cache, row_ids, new_rows,
                                                  active)
@@ -219,9 +230,8 @@ class ServeCompileCache:
         rows0 = self._rep(jnp.zeros((rows,), jnp.int32))
         start0 = self._rep(jnp.zeros((rows,), jnp.int32))
         act0 = self._rep(jnp.zeros((rows,), bool))
-        fn_c = jax.jit(chunk_fn, donate_argnums=(1,)).lower(params, cache0, tokens0, rows0,
-                                       start0, act0).compile()
-        self.compile_count += 1
+        fn_c = self._compile(chunk_fn, params, cache0, tokens0, rows0,
+                             start0, act0, donate=True)
         step = ChunkStep(rows=rows, chunk=int(chunk), fn=fn_c)
         self._steps[key] = step
         return step

@@ -227,11 +227,10 @@ class ServingEngine:
             # LUT GEMM. The plan fingerprint is already fixed (artifacts
             # are derived content and excluded from comp hashing).
             from repro.core.lm_compress import attach_serve_artifacts
-            from repro.kernels.lut_matmul.ops import default_interpret
+            from repro.kernels import resolve_interpret
 
-            use_ref = config.lut_use_ref
-            if use_ref is None:
-                use_ref = default_interpret()   # jnp oracle off-TPU
+            # None: the compiled kernel on a TPU, the jnp oracle elsewhere
+            use_ref = resolve_interpret(config.lut_use_ref)
             if config.autotune_cache:
                 from repro.kernels.lut_matmul.autotune import \
                     get_default_autotuner
