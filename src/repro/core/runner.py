@@ -28,6 +28,7 @@ from repro.data.synthetic import SyntheticImages
 from repro.nn.cnn import CNNModel
 from repro.nn.layers import QuantConfig
 from repro.nn.spec import init_params
+from repro.obs import span
 from repro.optim.optimizers import adamw, apply_updates
 
 
@@ -315,22 +316,29 @@ class CnnRunner:
         Each layer's sampled tiles run as ONE batched kernel/oracle
         invocation (`repro.core.profiler`), sharded over `profile_mesh` when
         set. The result is cached on the runner so `energy_models` (and the
-        schedule's ΔE refreshes) can reuse it without re-tracing.
+        schedule's ΔE refreshes) can reuse it without re-tracing. Host spans
+        (`repro.obs.span`): ``profile.taps``, then per layer
+        ``profile.layer`` (stat ``layer``) around ``profile.inputs`` and
+        ``profile.stats``.
         """
-        taps = self.capture_taps(params, state, comp, n_batches)
+        with span("profile.taps"):
+            taps = self.capture_taps(params, state, comp, n_batches)
         out: Dict[str, LayerStats] = {}
         for cl in self.model.comp_layers:
-            w_mat, x_col = self.layer_trace_inputs(cl, taps[cl.name])
-            # crc32, not hash(): str hash is salted per interpreter run,
-            # which would resample tiles (and flip schedule decisions) on
-            # every invocation of the same script
-            out[cl.name] = collect_layer_stats(
-                w_mat, x_col, max_tiles=max_tiles,
-                key=jax.random.PRNGKey(
-                    zlib.crc32(cl.name.encode()) % (2**31)),
-                use_kernel=self.use_kernel_stats,
-                mesh=self.profile_mesh,
-            )
+            with span("profile.layer", layer=cl.name):
+                with span("profile.inputs"):
+                    w_mat, x_col = self.layer_trace_inputs(cl, taps[cl.name])
+                # crc32, not hash(): str hash is salted per interpreter run,
+                # which would resample tiles (and flip schedule decisions)
+                # on every invocation of the same script
+                with span("profile.stats"):
+                    out[cl.name] = collect_layer_stats(
+                        w_mat, x_col, max_tiles=max_tiles,
+                        key=jax.random.PRNGKey(
+                            zlib.crc32(cl.name.encode()) % (2**31)),
+                        use_kernel=self.use_kernel_stats,
+                        mesh=self.profile_mesh,
+                    )
         self._stats_cache = out
         return out
 

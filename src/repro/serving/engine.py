@@ -34,6 +34,12 @@ step); `metrics.summarize` reports the gap to the per-request charge as
 ``energy_eu_overhead`` and a ``slot_utilization`` ratio. Slot-level refill
 is the mechanism that drives that overhead toward zero.
 
+Each step opens host spans on the profiler's trace (`repro.obs.span`):
+``engine.step`` around it, and inside ``engine.refill``, ``engine.chunk``,
+``engine.prefill``, ``engine.decode`` (dispatch only), ``engine.fetch``
+(the blocking read of logits) and ``engine.sample``, each with its row and
+token counts as stats (docs/serving.md, "Spans").
+
 The engine serves exactly one compression variant, identified by a
 `repro.serving.fleet.PlanHandle` (``plan=``): the handle's comp tree drives
 the compressed fake-quant forward, and its *content fingerprint* — not a
@@ -63,6 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.nn.layers import QuantConfig
+from repro.obs import span
 from repro.serving.bucketing import (
     BucketSpec,
     EngineConfig,
@@ -460,28 +467,30 @@ class ServingEngine:
         prompts = pad_prompts([r.prompt for r in taken], bucket,
                               self.config.pad_token)
         t_admit = time.perf_counter()
-        logits, kv = fns.prefill(self.params, self._place(prompts))
+        with span("engine.prefill", rows=bucket.batch):
+            logits, kv = fns.prefill(self.params, self._place(prompts))
         self.executed_positions += bucket.batch * bucket.prompt_len
-        vocab = self.model.cfg.vocab
-        last = np.asarray(logits[:, -1, :vocab])
+        last = self._fetch(logits, -1)
 
         slots: List[_Slot] = []
         tok = np.zeros((bucket.batch, 1), np.int32)
-        t_first = time.perf_counter()
-        for i in range(bucket.batch):
-            slot = None
-            if i < len(taken):
-                stats = self._stats_pending.pop(taken[i].rid)
-                stats.bucket = bucket.key()
-                stats.t_admitted = t_admit
-                slot = _Slot(taken[i], stats)
-                slots.append(slot)
-            tok[i, 0] = self._sample_row(last[i], slot)
-            if slot is not None:
-                slot.tokens.append(int(tok[i, 0]))
-                slot.stats.t_first_token = t_first
-        wave = _Wave(bucket, slots, fns, kv, self._place(tok))
-        self._finish_done(wave)
+        with span("engine.sample", tokens=len(taken)) as sp:
+            t_first = time.perf_counter()
+            for i in range(bucket.batch):
+                slot = None
+                if i < len(taken):
+                    stats = self._stats_pending.pop(taken[i].rid)
+                    stats.bucket = bucket.key()
+                    stats.t_admitted = t_admit
+                    slot = _Slot(taken[i], stats)
+                    slots.append(slot)
+                tok[i, 0] = self._sample_row(last[i], slot)
+                if slot is not None:
+                    slot.tokens.append(int(tok[i, 0]))
+                    slot.stats.t_first_token = t_first
+            wave = _Wave(bucket, slots, fns, kv, self._place(tok))
+            self._finish_done(wave)
+            sp.set_metadata(finished=sum(s.done for s in slots))
         if not wave.done:
             self._waves.append(wave)
         return True
@@ -489,22 +498,37 @@ class ServingEngine:
     # ------------------------------------------------- decode (wave modes)
 
     def _step(self, wave: _Wave) -> None:
-        logits, wave.cache = wave.fns.decode(self.params, wave.cache, wave.tok)
+        n_active = sum(not s.done for s in wave.slots)
+        with span("engine.decode", rows=wave.bucket.batch, active=n_active):
+            logits, wave.cache = wave.fns.decode(self.params, wave.cache,
+                                                 wave.tok)
         self.executed_positions += wave.bucket.batch
-        vocab = self.model.cfg.vocab
-        rows = np.asarray(logits[:, 0, :vocab])
+        rows = self._fetch(logits, 0)
         tok = np.zeros((wave.bucket.batch, 1), np.int32)
-        t = time.perf_counter()
-        for i in range(wave.bucket.batch):
-            slot = wave.slots[i] if i < len(wave.slots) else None
-            active = slot is not None and not slot.done
-            tok[i, 0] = self._sample_row(rows[i], slot if active else None)
-            if active:
-                slot.tokens.append(int(tok[i, 0]))
-                if slot.done:
-                    slot.stats.t_finish = t
-        wave.tok = self._place(tok)
-        self._finish_done(wave)
+        with span("engine.sample", tokens=n_active) as sp:
+            t = time.perf_counter()
+            for i in range(wave.bucket.batch):
+                slot = wave.slots[i] if i < len(wave.slots) else None
+                active = slot is not None and not slot.done
+                tok[i, 0] = self._sample_row(rows[i], slot if active else None)
+                if active:
+                    slot.tokens.append(int(tok[i, 0]))
+                    if slot.done:
+                        slot.stats.t_finish = t
+            wave.tok = self._place(tok)
+            self._finish_done(wave)
+            sp.set_metadata(finished=n_active - sum(not s.done
+                                                    for s in wave.slots))
+
+    def _fetch(self, logits, position: Optional[int] = None) -> np.ndarray:
+        """Logits on the host, sliced off the padded vocabulary: a (rows, V)
+        block whole, or one ``position`` of a (rows, L, V) block. Every
+        device-to-host read of logits goes through here."""
+        vocab = slice(None, self.model.cfg.vocab)
+        idx = (slice(None), vocab) if position is None else \
+            (slice(None), position, vocab)
+        with span("engine.fetch", rows=int(logits.shape[0])):
+            return np.asarray(logits[idx])
 
     def _finish_done(self, wave: _Wave) -> None:
         t = time.perf_counter()
@@ -590,23 +614,27 @@ class ServingEngine:
             s = g.slots[r]
             toks[j] = s.chunks[s.next_chunk]
             row_ids[j], start[j], active[j] = r, s.start, True
-        logits, g.cache = step.fn(
-            self.params, g.cache, self._place_rep(toks),
-            self._place_rep(row_ids), self._place_rep(start),
-            self._place_rep(active))
+        with span("engine.chunk", size=size, rows=n_rows, active=len(rows)):
+            logits, g.cache = step.fn(
+                self.params, g.cache, self._place_rep(toks),
+                self._place_rep(row_ids), self._place_rep(start),
+                self._place_rep(active))
         self.executed_positions += n_rows * size
-        finishing = [j for j, r in enumerate(rows)
-                     if g.slots[r].next_chunk + 1 == len(g.slots[r].chunks)]
-        last = None
-        if finishing:
-            vocab = self.model.cfg.vocab
-            last = np.asarray(logits[:, :vocab])
-        t = time.perf_counter()
-        for j, r in enumerate(rows):
+        for r in rows:
             s = g.slots[r]
             s.next_chunk += 1
             s.start += size
-            if not s.prefilling:
+        # rows whose prompt this chunk completed sample their first token
+        finishing = [(j, r) for j, r in enumerate(rows)
+                     if not g.slots[r].prefilling]
+        if not finishing:
+            return
+        last = self._fetch(logits)
+        with span("engine.sample", tokens=len(finishing)) as sp:
+            t = time.perf_counter()
+            finished = 0
+            for j, r in finishing:
+                s = g.slots[r]
                 tok = self._sample_row(last[j], s)
                 s.tokens.append(tok)
                 s.stats.t_first_token = t
@@ -615,6 +643,8 @@ class ServingEngine:
                     s.stats.t_finish = t
                     self._complete(s)
                     g.slots[r] = None
+                    finished += 1
+            sp.set_metadata(finished=finished)
 
     def _decode_group(self, g: _SlotGroup) -> bool:
         """One decode step over the group's rows that hold decoding slots."""
@@ -624,22 +654,26 @@ class ServingEngine:
             return False
         act = np.zeros((g.step.batch,), bool)
         act[rows] = True
-        logits, g.cache = g.step.decode(
-            self.params, g.cache, self._place_rep(g.tok),
-            self._place_rep(act))
+        with span("engine.decode", rows=g.step.batch, active=len(rows)):
+            logits, g.cache = g.step.decode(
+                self.params, g.cache, self._place_rep(g.tok),
+                self._place_rep(act))
         self.executed_positions += g.step.batch
-        vocab = self.model.cfg.vocab
-        out = np.asarray(logits[:, 0, :vocab])
-        t = time.perf_counter()
-        for r in rows:
-            s = g.slots[r]
-            tok = self._sample_row(out[r], s)
-            s.tokens.append(tok)
-            g.tok[r, 0] = tok
-            if s.done:
-                s.stats.t_finish = t
-                self._complete(s)
-                g.slots[r] = None
+        out = self._fetch(logits, 0)
+        with span("engine.sample", tokens=len(rows)) as sp:
+            t = time.perf_counter()
+            finished = 0
+            for r in rows:
+                s = g.slots[r]
+                tok = self._sample_row(out[r], s)
+                s.tokens.append(tok)
+                g.tok[r, 0] = tok
+                if s.done:
+                    s.stats.t_finish = t
+                    self._complete(s)
+                    g.slots[r] = None
+                    finished += 1
+            sp.set_metadata(finished=finished)
         return True
 
     # ----------------------------------------------------------------- run
@@ -650,24 +684,29 @@ class ServingEngine:
         One iteration is one refill + chunk + decode pass (slot mode) or one
         admit + lockstep-decode pass (wave/oneshot). The fleet router drains
         several engines by interleaving their steps so no variant
-        head-of-line blocks another."""
+        head-of-line blocks another. An idle call opens no span."""
         if self.mode == "engine":
             if not (self._queue or any(g.busy for g in self._groups)):
                 return False
-            self._refill_slots()
-            for g in self._groups:
-                self._chunk_steps(g)
-            for g in self._groups:
-                self._decode_group(g)
-            return True
-        if not (self._queue or self._waves):
+        elif not (self._queue or self._waves):
             return False
-        while self._queue and len(self._waves) < self.max_inflight:
-            if not self._admit():
-                break
-        for wave in list(self._waves):
-            self._step(wave)
-        return True
+        with span("engine.step", queued=len(self._queue)):
+            if self.mode == "engine":
+                with span("engine.refill") as sp:
+                    queued = len(self._queue)
+                    self._refill_slots()
+                    sp.set_metadata(admitted=queued - len(self._queue))
+                for g in self._groups:
+                    self._chunk_steps(g)
+                for g in self._groups:
+                    self._decode_group(g)
+                return True
+            while self._queue and len(self._waves) < self.max_inflight:
+                if not self._admit():
+                    break
+            for wave in list(self._waves):
+                self._step(wave)
+            return True
 
     def run(self) -> Dict[int, ServeResult]:
         """Drain the queue: admit + decode until every request completes."""
