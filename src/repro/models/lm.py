@@ -288,10 +288,17 @@ class LMModel:
     ) -> Tuple[jax.Array, dict]:
         """One token for every sequence in the batch. Returns (logits, cache).
 
-        ``cache["pos"]`` is per-sequence (B,). With ``active`` given, rows
-        where it is False keep their cache and position untouched (their
-        logits are garbage and must be ignored) — this is what lets a slot
-        group decode while some slots are empty or mid-prefill.
+        ``cache["pos"]`` is per-sequence (B,). The layer scan only reads the
+        cache: attention blocks emit the new token's K/V rows, and after the
+        scan one scatter per leaf writes them at ``[row, pos mod Smax]``, so
+        a donated cache is updated in place instead of rewritten. Recurrent
+        blocks' states are replaced whole; cross-attention K/V are kept.
+
+        With ``active`` given, rows where it is False keep their cache and
+        position untouched (their logits are garbage and must be ignored):
+        their K/V writes are dropped and ``_merge_active`` keeps their state
+        and position. This is what lets a slot group decode while some
+        slots are empty or mid-prefill.
         """
         cfg = self.cfg
         pos = cache["pos"]
@@ -314,30 +321,45 @@ class LMModel:
                 layer_params, layer_cache, layer_comp = xs
             else:
                 (layer_params, layer_cache), layer_comp = xs, None
-            new_caches = {}
+            outs = {}
             for i, bt in enumerate(cfg.pattern):
                 ci = None if layer_comp is None else layer_comp.get(f"g{i}")
-                h, c_new = apply_block_decode(
+                h, outs[f"g{i}"] = apply_block_decode(
                     layer_params[f"g{i}"], h, layer_cache[f"g{i}"], pos, cfg,
                     bt, qcfg=qcfg, comp=ci)
-                new_caches[f"g{i}"] = c_new
-            return h, new_caches
+            return h, outs
 
-        new_cache = {"groups": cache["groups"], "tail": {}, "pos": pos + 1}
+        outs = {"groups": {}, "tail": {}}
         if self.n_rep > 0:
             xs = (params["blocks"], cache["groups"])
             if blocks_comp is not None:
                 xs = (params["blocks"], cache["groups"], blocks_comp)
-            x, group_caches = jax.lax.scan(macro_body, x, xs)
-            new_cache["groups"] = group_caches
+            x, outs["groups"] = jax.lax.scan(macro_body, x, xs)
         for j in range(self.n_tail):
             bt = cfg.pattern[j]
             cj = None if tail_comp is None else tail_comp.get(f"t{j}")
-            x, c_new = apply_block_decode(
+            x, outs["tail"][f"t{j}"] = apply_block_decode(
                 params["tail"][f"t{j}"], x, cache["tail"][f"t{j}"], pos, cfg,
                 bt, qcfg=qcfg, comp=cj)
-            new_cache["tail"][f"t{j}"] = c_new
 
+        b = tokens.shape[0]
+        rows = jnp.arange(b, dtype=jnp.int32)
+        if active is not None:
+            # out of range (and still distinct): the scatter drops the write
+            rows = jnp.where(active.astype(bool), rows, rows + b)
+        new_cache = {"groups": dict(cache["groups"]),
+                     "tail": dict(cache["tail"]), "pos": pos + 1}
+        for sect, key, bt, axis in self._cache_blocks():
+            old, out = cache[sect][key], outs[sect][key]
+            if bt in ("attn", "local"):
+                slots = jnp.mod(pos, old["k"].shape[axis + 1])
+                at = (slice(None),) * axis + (rows, slots)
+                new_cache[sect][key] = {**old, **{
+                    n: old[n].at[at].set(out[n], mode="drop",
+                                         unique_indices=True)
+                    for n in ("k", "v")}}
+            else:
+                new_cache[sect][key] = out
         if active is not None:
             new_cache = self._merge_active(cache, new_cache, active)
 
@@ -345,12 +367,24 @@ class LMModel:
         logits = self._unembed(params, x, shard_logits)
         return logits, new_cache
 
-    @staticmethod
-    def _merge_active(old_cache: dict, new_cache: dict, active) -> dict:
-        """Keep inactive rows' cache untouched. Requires per-row pos (B,).
+    def _cache_blocks(self):
+        """(section, key, block type, batch axis) of each block's cache:
+        `groups` leaves carry a leading layer-stack axis, `tail` leaves
+        have batch leading."""
+        if self.n_rep > 0:
+            for i, bt in enumerate(self.cfg.pattern):
+                yield "groups", f"g{i}", bt, 1
+        for j in range(self.n_tail):
+            yield "tail", f"t{j}", self.cfg.pattern[j], 0
 
-        `groups` leaves carry a leading layer-stack axis (batch is axis 1);
-        `tail` and `pos` leaves have batch leading.
+    def _merge_active(self, old_cache: dict, new_cache: dict, active) -> dict:
+        """Keep inactive rows' position and recurrent state. Requires per-row
+        pos (B,).
+
+        Only what a decode step replaces whole goes through this masked
+        select: ``pos`` and the rglru/ssm state leaves, which are (B, d)
+        sized. Attention K/V leaves pass through: the step's scatter already
+        dropped inactive rows' writes.
         """
         act = active.astype(bool)
 
@@ -361,13 +395,14 @@ class LMModel:
                 return jnp.where(act.reshape(shape), new, old)
             return f
 
-        return {
-            "groups": jax.tree.map(merge(1), new_cache["groups"],
-                                   old_cache["groups"]),
-            "tail": jax.tree.map(merge(0), new_cache["tail"],
-                                 old_cache["tail"]),
-            "pos": jnp.where(act, new_cache["pos"], old_cache["pos"]),
-        }
+        merged = {"groups": dict(new_cache["groups"]),
+                  "tail": dict(new_cache["tail"]),
+                  "pos": jnp.where(act, new_cache["pos"], old_cache["pos"])}
+        for sect, key, bt, axis in self._cache_blocks():
+            if bt not in ("attn", "local"):
+                merged[sect][key] = jax.tree.map(
+                    merge(axis), new_cache[sect][key], old_cache[sect][key])
+        return merged
 
     # --------------------------------------------------------------- prefill
 

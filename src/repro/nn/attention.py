@@ -221,6 +221,7 @@ def blocked_attention(
 def decode_attention(
     q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, dims: AttnDims, *,
     cur_pos: jax.Array, cache_positions: Optional[jax.Array] = None,
+    kv_new: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> jax.Array:
     """Single-step attention over a cache.
 
@@ -229,6 +230,9 @@ def decode_attention(
     ``cache_positions[..., i]`` (default: identity, i.e. contiguous cache);
     ``cache_positions`` may be per-sequence (B, Smax) when rows sit at
     independent offsets (slot-level continuous batching).
+
+    ``kv_new`` ((B, Hkv, D) each) is the new token's own key and value, not
+    in the cache: it joins the softmax as one more term at ``cur_pos``.
     """
     b, _, hq, hd = q.shape
     smax, hkv = k_cache.shape[1], k_cache.shape[2]
@@ -236,6 +240,9 @@ def decode_attention(
     scale = 1.0 / (hd ** 0.5)
     qg = q.reshape(b, hkv, g, hd)
     s = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache).astype(jnp.float32) * scale
+    if kv_new is not None:
+        s_new = jnp.einsum("bhgd,bhd->bhg", qg, kv_new[0]).astype(jnp.float32)
+        s = jnp.concatenate([s, s_new[..., None] * scale], axis=-1)
     if dims.softcap > 0:
         s = dims.softcap * jnp.tanh(s / dims.softcap)
     pos = cache_positions if cache_positions is not None else jnp.arange(smax)
@@ -247,9 +254,16 @@ def decode_attention(
     valid = (pos <= cur) & (pos >= 0)         # (B or 1, Smax)
     if dims.window > 0:
         valid &= pos > cur - dims.window
+    if kv_new is not None:                    # the new token sees itself
+        valid = jnp.concatenate(
+            [jnp.broadcast_to(valid, (b, smax)), jnp.ones((b, 1), bool)],
+            axis=-1)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgk,bkhd->bhgd", p.astype(v_cache.dtype), v_cache)
+    out = jnp.einsum("bhgk,bkhd->bhgd", p[..., :smax].astype(v_cache.dtype),
+                     v_cache)
+    if kv_new is not None:
+        out = out + p[..., smax:].astype(v_cache.dtype) * kv_new[1][:, :, None]
     # a cache wider than the compute dtype (f32 cache, bf16 compute) must
     # not widen the residual stream: the layer scan carries it at q's dtype
     return out.reshape(b, 1, hq, hd).astype(q.dtype)
@@ -343,11 +357,20 @@ def apply_attention_decode(
     name: str = "attn",
     cross_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, dict]:
-    """One decode step; returns (output (B, 1, d), updated cache).
+    """One decode step; returns (output (B, 1, d), the new token's K/V rows).
 
-    ``pos`` may be per-sequence (B,): each row writes its own ring slot and
-    masks against its own position, which is what slot-level continuous
-    batching needs when rows of one batch sit at different depths.
+    The cache is only read. Each row attends over its cache with its own
+    slot ``pos mod Smax`` masked out (it holds an older position, or
+    nothing), plus the new token's own key and value as one more term of
+    the same softmax: the positions attended are those of a cache with the
+    new token written in. The returned ``{"k", "v"}`` rows are (B, Hkv, D)
+    in the cache's dtype; the caller writes them at slot ``pos mod Smax``
+    (``LMModel.decode_step``). With ``cross_kv`` the output attends over
+    the encoder's K/V and the rows are empty.
+
+    ``pos`` may be per-sequence (B,): each row masks against its own
+    position, which is what slot-level continuous batching needs when rows
+    of one batch sit at different depths.
     """
     b = x.shape[0]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
@@ -359,32 +382,33 @@ def apply_attention_decode(
             q, cross_kv[0], cross_kv[1],
             dataclasses.replace(dims, causal=False, window=0),
             cur_pos=jnp.int32(1 << 30))
-        return _project(params, out, qcfg, comp, name, "wo"), cache
+        return _project(params, out, qcfg, comp, name, "wo"), {}
 
     k_new = _project(params, x, qcfg, comp, name, "wk", "bk")
     v_new = _project(params, x, qcfg, comp, name, "wv", "bv")
     if dims.rope_theta > 0:
         q = apply_rope(q, positions, dims.rope_theta)
         k_new = apply_rope(k_new, positions, dims.rope_theta)
+    k_row = k_new[:, 0].astype(cache["k"].dtype)
+    v_row = v_new[:, 0].astype(cache["v"].dtype)
 
     smax = cache["k"].shape[1]
     idx = jnp.arange(smax, dtype=jnp.int32)
-    # ring-buffer write for windowed layers, linear write otherwise; a pure
-    # select (not dynamic_update_slice) so each row can hit its own slot.
-    write = idx[None, :] == jnp.mod(pos_b, smax)[:, None]  # (B, Smax)
-    k_cache = jnp.where(write[..., None, None],
-                        k_new.astype(cache["k"].dtype), cache["k"])
-    v_cache = jnp.where(write[..., None, None],
-                        v_new.astype(cache["v"].dtype), cache["v"])
     # slot i holds the largest position congruent to i (mod smax) that is
-    # <= pos; slots never written yet resolve to negative positions, which
-    # the validity mask in decode_attention rejects.
+    # <= pos (ring layout for windowed layers, linear otherwise); slots
+    # never written yet resolve to negative positions, which the validity
+    # mask in decode_attention rejects. The row's own slot (position pos)
+    # still holds an older entry or nothing: it goes to -1, and the new
+    # token's own term stands in for it.
     cache_positions = idx[None, :] + (
         (pos_b[:, None] - idx[None, :]) // smax) * smax  # (B, Smax)
-    out = decode_attention(q, k_cache, v_cache, dims, cur_pos=pos_b,
-                           cache_positions=cache_positions)
+    cache_positions = jnp.where(cache_positions == pos_b[:, None], -1,
+                                cache_positions)
+    out = decode_attention(q, cache["k"], cache["v"], dims, cur_pos=pos_b,
+                           cache_positions=cache_positions,
+                           kv_new=(k_row, v_row))
     out = _project(params, out, qcfg, comp, name, "wo")
-    return out, {"k": k_cache, "v": v_cache}
+    return out, {"k": k_row, "v": v_row}
 
 
 def apply_attention_chunk(

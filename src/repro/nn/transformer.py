@@ -242,25 +242,26 @@ def apply_block_decode(
     qcfg: QuantConfig = QuantConfig.off(),
     comp=None,
 ) -> Tuple[jax.Array, dict]:
+    """One decode step through a block, reading its cache; returns (x, out).
+
+    Attention blocks give the new token's K/V rows ``{"k", "v"}`` (B, Hkv,
+    D) for the caller to write into the cache (cross-attention K/V are only
+    read); recurrent blocks give their whole new state.
+    """
     h = apply_norm(params["ln1"], x, cfg)
-    new_cache = dict(cache)
     if block_type in ("attn", "local"):
         dims = cfg.attn_dims(block_type == "local")
-        kv_cache = {"k": cache["k"], "v": cache["v"]}
-        mix, kv_new = A.apply_attention_decode(
-            params["attn"], h, kv_cache, pos, dims, qcfg=qcfg, comp=comp,
+        mix, out = A.apply_attention_decode(
+            params["attn"], h, cache, pos, dims, qcfg=qcfg, comp=comp,
             name="attn")
-        new_cache.update(kv_new)
     elif block_type == "rglru":
-        mix, rg_new = RG.apply_rglru_decode(
+        mix, out = RG.apply_rglru_decode(
             params["rglru"], h, cache, cfg.rglru_dims(), qcfg=qcfg, comp=comp,
             name="rglru")
-        new_cache = rg_new
     elif block_type == "ssm":
-        mix, ssm_new = SSM.apply_ssm_decode(
+        mix, out = SSM.apply_ssm_decode(
             params["ssm"], h, cache, cfg.ssm_dims(), qcfg=qcfg, comp=comp,
             name="ssm")
-        new_cache = ssm_new
     else:
         raise ValueError(block_type)
     x = x + mix
@@ -273,7 +274,7 @@ def apply_block_decode(
         x = x + xa
 
     if block_type == "ssm":
-        return x, new_cache
+        return x, out
 
     h = apply_norm(params["ln2"], x, cfg)
     if cfg.is_moe and block_type in ("attn", "local"):
@@ -281,7 +282,7 @@ def apply_block_decode(
                              comp=comp, name="moe")
     else:
         y = apply_ffn(params["mlp"], h, cfg, qcfg=qcfg, comp=comp, name="mlp")
-    return x + y, new_cache
+    return x + y, out
 
 
 def apply_block_chunk(

@@ -3,9 +3,12 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.models.config import ArchConfig
-from repro.models.lm import build_lm
+from repro.models.lm import _sinusoid, build_lm
+from repro.nn import attention as A
+from repro.nn import transformer as T
 from repro.nn.layers import QuantConfig
 from repro.nn.spec import init_params
 
@@ -182,3 +185,149 @@ def test_window_equals_full_when_window_large():
     lf, _ = m_f.forward(params, toks, q_block=8, kv_block=8)
     ll, _ = m_l.forward(params, toks, q_block=8, kv_block=8)
     np.testing.assert_allclose(np.asarray(lf), np.asarray(ll), atol=1e-5)
+
+
+# ------------------------------------------------- decode writes in place
+
+_NEW_ATTENTION_DECODE = A.apply_attention_decode
+
+
+def _old_attention_decode(params, x, cache, pos, dims, *, qcfg, comp, name,
+                          cross_kv=None):
+    """The decode write this repo used to have: the new token's K/V go into
+    the whole cache by a select, and attention reads the written cache."""
+    if cross_kv is not None:
+        return _NEW_ATTENTION_DECODE(params, x, cache, pos, dims, qcfg=qcfg,
+                                     comp=comp, name=name, cross_kv=cross_kv)
+    b = x.shape[0]
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    q = A._project(params, x, qcfg, comp, name, "wq", "bq")
+    k_new = A._project(params, x, qcfg, comp, name, "wk", "bk")
+    v_new = A._project(params, x, qcfg, comp, name, "wv", "bv")
+    if dims.rope_theta > 0:
+        q = A.apply_rope(q, pos_b[:, None], dims.rope_theta)
+        k_new = A.apply_rope(k_new, pos_b[:, None], dims.rope_theta)
+    smax = cache["k"].shape[1]
+    idx = jnp.arange(smax, dtype=jnp.int32)
+    write = (idx[None, :] == jnp.mod(pos_b, smax)[:, None])[..., None, None]
+    k_cache = jnp.where(write, k_new.astype(cache["k"].dtype), cache["k"])
+    v_cache = jnp.where(write, v_new.astype(cache["v"].dtype), cache["v"])
+    cache_positions = idx[None, :] + (
+        (pos_b[:, None] - idx[None, :]) // smax) * smax
+    out = A.decode_attention(q, k_cache, v_cache, dims, cur_pos=pos_b,
+                             cache_positions=cache_positions)
+    return (A._project(params, out, qcfg, comp, name, "wo"),
+            {"k": k_cache, "v": v_cache})
+
+
+def _old_decode_step(m, params, cache, tokens, active):
+    """Reference decode: the whole cache through the layer scan's `ys`, then
+    a masked select of every leaf for the inactive rows."""
+    cfg = m.cfg
+    pos = cache["pos"]
+    x = jnp.take(params["embed"]["table"], tokens, axis=0).astype(cfg.cdtype)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(np.sqrt(cfg.d_model), cfg.cdtype)
+    if cfg.encoder_decoder:
+        x = x + _sinusoid(pos[:, None], cfg.d_model).astype(x.dtype)
+
+    def block(p, h, c, bt):
+        h, out = T.apply_block_decode(p, h, c, pos, cfg, bt)
+        return h, {**c, **out}
+
+    def body(h, xs):
+        p, c = xs
+        new = {}
+        for i, bt in enumerate(cfg.pattern):
+            h, new[f"g{i}"] = block(p[f"g{i}"], h, c[f"g{i}"], bt)
+        return h, new
+
+    new = {"groups": cache["groups"], "tail": {}, "pos": pos + 1}
+    if m.n_rep > 0:
+        x, new["groups"] = jax.lax.scan(body, x,
+                                        (params["blocks"], cache["groups"]))
+    for j in range(m.n_tail):
+        x, new["tail"][f"t{j}"] = block(params["tail"][f"t{j}"], x,
+                                        cache["tail"][f"t{j}"],
+                                        cfg.pattern[j])
+
+    def merge(axis):
+        def f(n, o):
+            shape = [1] * n.ndim
+            shape[axis] = active.shape[0]
+            return jnp.where(active.reshape(shape), n, o)
+        return f
+
+    new = {"groups": jax.tree.map(merge(1), new["groups"], cache["groups"]),
+           "tail": jax.tree.map(merge(0), new["tail"], cache["tail"]),
+           "pos": jnp.where(active, new["pos"], pos)}
+    x = T.apply_norm(params["final_norm"], x, cfg)
+    return m._unembed(params, x), new
+
+
+_WRITE_CASES = {
+    "dense": (_mk(), 1e-4),
+    "local_ring": (_mk(n_layers=5, pattern=("local", "local", "attn"),
+                       window=6), 1e-4),
+    "moe": (_mk(family="moe", n_experts=4, moe_top_k=2, moe_d_ff=64,
+                capacity_factor=2.0), 1e-3),
+    "rglru_hybrid": (_mk(family="hybrid", pattern=("rglru", "rglru", "local"),
+                         window=6, n_layers=5, rnn_width=64, ffn="geglu",
+                         embed_scale=True), 1e-3),
+    "ssm_hybrid": (_mk(family="hybrid", pattern=("ssm", "attn"), n_layers=3,
+                       ssm_d_state=32, ssm_head_dim=32, ssm_chunk=8), 1e-3),
+    "encdec": (_mk(family="audio", encoder_decoder=True, n_enc_layers=2,
+                   n_layers=2, ffn="gelu", norm="layernorm",
+                   rope_theta=0.0), 1e-3),
+}
+
+
+@pytest.mark.parametrize("case", list(_WRITE_CASES))
+def test_decode_row_write_matches_whole_cache_write(case, monkeypatch):
+    """The decode step that writes one K/V slot per row equals the one that
+    rewrote the whole cache: over several steps with mixed `active` masks
+    and rows at different depths (one at Smax - 1, one wrapping a ring)."""
+    cfg, tol = _WRITE_CASES[case]
+    m = build_lm(cfg)
+    params = init_params(jax.random.PRNGKey(0), m.spec)
+    b, smax = 4, 16
+    spec = m.cache_spec(b, smax, jnp.float32, cross_len=8 if
+                        cfg.encoder_decoder else 0)
+    keys = iter(jax.random.split(jax.random.PRNGKey(3), 64))
+    cache = {sect: jax.tree.map(lambda s: 0.5 * jax.random.normal(
+        next(keys), s.shape, s.dtype), spec[sect]) for sect in ("groups",
+                                                               "tail")}
+    cache["pos"] = jnp.asarray([0, 5, 11, smax - 1], jnp.int32)
+    masks = [[1, 1, 1, 1], [1, 0, 1, 0], [0, 1, 0, 1], [1, 1, 0, 1],
+             [0, 0, 1, 0], [1, 0, 1, 1]]
+    step = jax.jit(m.decode_step)
+
+    @jax.jit
+    def old_step(p, c, t, a):
+        with monkeypatch.context() as mp:     # patched while tracing only
+            mp.setattr(A, "apply_attention_decode", _old_attention_decode)
+            return _old_decode_step(m, p, c, t, a)
+
+    ref_cache = cache
+    for t, mask in enumerate(masks):
+        active = jnp.asarray(mask, bool)
+        toks = jax.random.randint(jax.random.PRNGKey(10 + t), (b, 1), 0,
+                                  cfg.vocab)
+        lg, new = step(params, cache, toks, active=active)
+        lg_ref, ref_cache = old_step(params, ref_cache, toks, active)
+        on = np.asarray(mask, bool)
+        err = np.max(np.abs(np.asarray(lg - lg_ref)[on, 0, :cfg.vocab]))
+        assert err < tol, (t, err)
+        for sect, axis in (("groups", 1), ("tail", 0)):
+            for key in cache[sect]:
+                for n in cache[sect][key]:
+                    a = np.asarray(new[sect][key][n])
+                    o = np.asarray(cache[sect][key][n])
+                    np.testing.assert_array_equal(
+                        np.take(a, np.flatnonzero(~on), axis=axis),
+                        np.take(o, np.flatnonzero(~on), axis=axis))
+                    np.testing.assert_allclose(
+                        a, ref_cache[sect][key][n], atol=tol, rtol=0)
+        np.testing.assert_array_equal(new["pos"],
+                                      np.asarray(cache["pos"]) + on)
+        cache = new

@@ -157,3 +157,36 @@ def test_olmo1b_decode_step_compiles(one_chip, plan):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES
+
+
+def test_olmo1b_decode_writes_cache_in_place(one_chip):
+    """The engine's decode (cache donated, `active`-masked) at olmo-1b
+    widths writes the new token's K/V into the cache in place: its
+    temporaries do not grow with the cache. A step that rewrites the
+    whole cache holds one whole-cache temporary, which grows with it."""
+    from repro.configs import get_config
+    from repro.models.lm import build_lm
+    from repro.nn.spec import init_params
+    from repro.serving import EngineConfig
+
+    model = build_lm(get_config("olmo-1b"))
+    ecfg = EngineConfig()
+    batch = ecfg.max_batch
+    params = _shapes(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), model.spec)), one_chip)
+    tok = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip)
+    active = jax.ShapeDtypeStruct((batch,), jnp.bool_, sharding=one_chip)
+    step = jax.jit(lambda p, c, t, a: model.decode_step(p, c, t, active=a),
+                   donate_argnums=(1,))
+    temp, cache_bytes = [], []
+    for total_len in (96, 1024):
+        cache = _shapes(model.cache_spec(batch, total_len,
+                                         jnp.dtype(ecfg.cache_dtype)),
+                        one_chip)
+        mem = step.lower(params, cache, tok, active).compile() \
+            .memory_analysis()
+        temp.append(mem.temp_size_in_bytes)
+        cache_bytes.append(sum(s.size * s.dtype.itemsize
+                               for s in jax.tree.leaves(cache)))
+    assert temp[1] - temp[0] < 0.05 * (cache_bytes[1] - cache_bytes[0]), \
+        (temp, cache_bytes)
